@@ -13,9 +13,10 @@ Timing interleaves seed/engine reps and reports median seconds per
 candidate plus a paired-ratio-median speedup
 (``common.{interleaved_times,paired_ratio_median}``): on this 2-core box
 back-to-back means drift 1.5-2x run to run, which used to make the
-threshold/block speedups look like regressions.  Each cell runs in a
-spawned subprocess so its ``peak_rss_mb`` — the memory story of the
-streaming engine — is its own high-water mark, not the grid's.
+threshold/block speedups look like regressions.  On the CPU each cell
+runs in a spawned subprocess so its ``peak_rss_mb`` — the memory story of
+the streaming engine — is its own high-water mark, not the grid's; on an
+accelerator every cell runs in this process, which holds the chip.
 
 Writes ``BENCH_aggregation.json`` at the repo root so the perf trajectory
 is tracked; emits the usual CSV rows for ``benchmarks.run``.
@@ -135,10 +136,19 @@ def bench_sharded_cell(*, d: int = SHARD_D, timing_d: int = SHARD_TIMING_D,
     }
 
 
+def _on_cpu() -> bool:
+    return jax.default_backend() == "cpu"
+
+
 def _sharded_measured_cell(**kwargs) -> dict:
-    """The sharded cell always runs in its own spawned process: the fake
-    device count is forced via ``XLA_FLAGS``, which only takes effect at
-    jax init, and ``run_isolated``'s child inherits the patched env."""
+    """On the CPU the sharded cell runs in its own spawned process: the
+    fake device count is forced via ``XLA_FLAGS``, which only takes effect
+    at jax init, and ``run_isolated``'s child inherits the patched env.
+    On an accelerator this process holds the chips, so the cell runs here,
+    over the devices it has."""
+    if not _on_cpu():
+        kwargs.setdefault("devices", len(jax.devices()))
+        return bench_sharded_cell(**kwargs)
     from .memprof import run_isolated
     devices = kwargs.get("devices", SHARD_DEVICES)
     flag = f"--xla_force_host_platform_device_count={devices}"
@@ -201,8 +211,9 @@ def bench_cell(d: int, n: int, vote_mode: str, compact_mode: str,
 
 
 def _measured_cell(*args, rss: bool, **kwargs) -> dict:
-    """One cell, in its own process when a peak-RSS reading is wanted."""
-    if not rss:
+    """One cell, in its own process when a CPU peak-RSS reading is wanted.
+    On an accelerator no child is spawned: this process holds the chip."""
+    if not rss or not _on_cpu():
         return bench_cell(*args, **kwargs)
     from .memprof import run_isolated
     cell, peak = run_isolated("benchmarks.aggregation_round:bench_cell",

@@ -3,7 +3,9 @@
   PYTHONPATH=src python -m benchmarks.run [--only fig2,tables,...] [--smoke]
 
 Prints ``name,value,derived`` CSV rows (see each module's docstring for the
-paper artifact it reproduces).  ``--smoke`` runs every section on a tiny
+paper artifact it reproduces).  A section that raises is recorded as a
+``<name>/ERROR`` row, the remaining sections still run, and the command
+exits 1.  ``--smoke`` runs every section on a tiny
 budget (seconds per section; sections that normally write tracked
 ``BENCH_*.json`` files write to a temp path instead) — the registry test
 exercises exactly this mode.
@@ -14,6 +16,8 @@ from __future__ import annotations
 import argparse
 import sys
 import time
+
+from repro.launch.cache import enable_compile_cache
 
 from . import (accuracy_vs_time, aggregation_ops, aggregation_round,
                async_throughput, compression_error, dataplane, faults,
@@ -47,16 +51,22 @@ def main(argv=None) -> int:
     ap.add_argument("--smoke", action="store_true",
                     help="tiny-budget run of every section (CI / registry test)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     names = args.only.split(",") if args.only else list(SECTIONS)
     print("name,value,derived")
+    failed = []
     for name in names:
         t0 = time.time()
         try:
             rows = SECTIONS[name](smoke=args.smoke)
-        except Exception as e:  # keep the harness running; record the failure
+        except Exception as e:  # run the other sections, then fail the run
             rows = [(f"{name}/ERROR", type(e).__name__, str(e)[:120])]
+            failed.append(name)
         emit(rows)
         print(f"# section {name} done in {time.time() - t0:.1f}s", file=sys.stderr)
+    if failed:
+        print(f"# sections failed: {','.join(failed)}", file=sys.stderr)
+        return 1
     return 0
 
 

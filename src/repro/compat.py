@@ -1,45 +1,20 @@
-"""Version-portability shims for the small jax API surface this repo pins.
+"""The one mesh builder every mesh in this repo goes through.
 
-``shard_map`` graduated from ``jax.experimental.shard_map`` (where its
-replication check is spelled ``check_rep``) to ``jax.shard_map`` (spelled
-``check_vma``), and ``jax.lax.axis_size`` only exists on newer jax (older
-generations read the traced axis frame).  Everything in this repo — and
-the test subprocesses that emulate meshes — goes through these wrappers so
-both jax generations work.
+``jax.make_mesh`` gives Explicit axes by default, under which a
+``shard_map`` or a gather called outside ``jax.set_mesh`` is refused; the
+repo's meshes are Auto-axis meshes built by :func:`make_mesh`.
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["shard_map", "axis_size", "make_mesh"]
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """``jax.shard_map`` across jax versions (check_vma <-> check_rep)."""
-    if hasattr(jax, "shard_map"):
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
+__all__ = ["make_mesh"]
 
 
-def axis_size(axis_name) -> int:
-    """Static size of a named mesh axis, inside shard_map/vmap tracing."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    frame = jax.core.axis_frame(axis_name)  # int on 0.4.x, frame earlier
-    return getattr(frame, "size", frame)
-
-
-def make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the API knows them
-    (jax.sharding.AxisType only exists on newer jax)."""
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with Auto axis types (over ``devices`` if given)."""
+    kw = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         **kw)

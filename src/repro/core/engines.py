@@ -29,7 +29,8 @@ from __future__ import annotations
 import dataclasses
 import warnings
 
-__all__ = ["EngineSpec", "get", "names", "register", "resolve", "run"]
+__all__ = ["EngineSpec", "get", "names", "register", "resolve", "run",
+           "with_pallas"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,24 +54,24 @@ class EngineSpec:
 
 def _run_monolithic(spec, u_stack, cfg, key, a):
     from .fediac import aggregate_stack
-    return aggregate_stack(u_stack, _with_pallas(cfg, spec), key, a=a)
+    return aggregate_stack(u_stack, with_pallas(cfg, spec), key, a=a)
 
 
 def _run_stream(spec, u_stack, cfg, key, a):
     from .stream_engine import aggregate_stream
-    return aggregate_stream(u_stack, _with_pallas(cfg, spec), key, a=a,
+    return aggregate_stream(u_stack, with_pallas(cfg, spec), key, a=a,
                             chunk=spec.chunk or None)
 
 
 def _run_sharded(spec, u_stack, cfg, key, a):
     from .shard_engine import aggregate_shard
-    return aggregate_shard(u_stack, _with_pallas(cfg, spec), key, a=a,
+    return aggregate_shard(u_stack, with_pallas(cfg, spec), key, a=a,
                            devices=spec.devices or None, axis=spec.axis)
 
 
 def _run_async(spec, u_stack, cfg, key, a):
     from repro.netsim.async_engine import aggregate_async_stack
-    return aggregate_async_stack(u_stack, _with_pallas(cfg, spec), key, a=a)
+    return aggregate_async_stack(u_stack, with_pallas(cfg, spec), key, a=a)
 
 
 _RUNNERS = {
@@ -150,9 +151,10 @@ def resolve(cfg) -> EngineSpec:
     return spec
 
 
-def _with_pallas(cfg, spec: EngineSpec):
+def with_pallas(cfg, spec: EngineSpec):
     """A cfg whose low-level ``use_pallas`` mechanism matches the spec
-    (``aggregate_stack``/``aggregate_stream`` read the cfg field)."""
+    (``aggregate_stack``/``aggregate_stream`` and the packet cores read
+    the cfg field)."""
     if getattr(cfg, "use_pallas", False) == spec.use_pallas:
         return cfg
     return dataclasses.replace(cfg, use_pallas=spec.use_pallas)

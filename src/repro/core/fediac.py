@@ -37,7 +37,6 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
 from repro.validate import (check_at_least, check_choice, check_interval,
                             require)
 
@@ -465,12 +464,12 @@ def fediac_allreduce(u: jax.Array, residual: jax.Array, key: jax.Array,
     # per-client key: fold in the client's linear index along the client axes.
     lin = jnp.int32(0)
     for ax in axes:
-        lin = lin * axis_size(ax) + jax.lax.axis_index(ax)
+        lin = lin * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
     key = jax.random.fold_in(key, lin)
     kv, kq = jax.random.split(key)
     n = 1
     for ax in axes:
-        n *= axis_size(ax)
+        n *= jax.lax.axis_size(ax)
 
     # ---- Phase 1: vote, then the "switch" sums 0/1 arrays.
     if cfg.vote_wire == "packed":

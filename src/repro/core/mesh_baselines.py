@@ -25,8 +25,6 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
-
 from .fediac import FediACConfig
 from .quantize import dequantize, quantize, scale_factor
 
@@ -40,7 +38,7 @@ def _axes(client_axes):
 def _n_clients(axes):
     n = 1
     for ax in axes:
-        n *= axis_size(ax)
+        n *= jax.lax.axis_size(ax)
     return n
 
 

@@ -56,7 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import make_mesh, shard_map
+from repro.compat import make_mesh
 
 from . import compaction, robust_agg, voting
 from .quantize import dequantize, quantize, scale_factor
@@ -93,7 +93,11 @@ def shard_geometry(d: int, n_dev: int, cfg) -> tuple[int, int]:
 
 
 def shard_mesh(devices: int | None = None, axis: str = "d"):
-    """A 1-D coordinate mesh over ``devices`` (default: all visible)."""
+    """A 1-D coordinate mesh over ``devices`` (default: all visible).
+
+    The engine builds its mesh here, so a caller that places ``u_stack``
+    with ``NamedSharding(shard_mesh(...), P(None, axis))`` hands it over
+    already sharded: the stack never lands whole on one device."""
     n_dev = int(devices) if devices else len(jax.devices())
     return make_mesh((n_dev,), (axis,))
 
@@ -324,9 +328,8 @@ def aggregate_shard(u_stack: jax.Array, cfg, key: jax.Array, *, a=None,
 
     n, d = u_stack.shape
     _check_shardable(cfg)
-    n_dev = int(devices) if devices else len(jax.devices())
-    s, width = shard_geometry(d, n_dev, cfg)
-    mesh = make_mesh((n_dev,), (axis,))
+    mesh = shard_mesh(devices, axis)
+    s, width = shard_geometry(d, mesh.size, cfg)
     keys = jax.random.split(key, 2 * n)
     vote_keys, q_keys = keys[:n], keys[n:]
     k = min(cfg.k(d), d)
@@ -388,10 +391,10 @@ def aggregate_shard(u_stack: jax.Array, cfg, key: jax.Array, *, a=None,
             delta_loc, res = _phase2_chunked(u_loc, p2, cs)
         return delta_loc, res, counts_loc
 
-    run = shard_map(body, mesh=mesh,
-                    in_specs=(P(None, axis), P(), P(), P()),
-                    out_specs=(P(axis), P(None, axis), P(axis)),
-                    check_vma=False)
+    run = jax.shard_map(body, mesh=mesh,
+                        in_specs=(P(None, axis), P(), P(), P()),
+                        out_specs=(P(axis), P(None, axis), P(axis)),
+                        check_vma=False)
     delta, residuals, counts = run(_pad_cols(u_stack, width), vote_keys,
                                    q_keys, a_arr)
     return (delta[:d], residuals[:, :d], counts[:d], round_traffic(cfg, d))
@@ -414,9 +417,8 @@ def shard_compress_stack(u_stack: jax.Array, cfg, f, q_keys: jax.Array,
     """
     n, d = u_stack.shape
     _check_shardable(cfg)
-    n_dev = int(devices) if devices else len(jax.devices())
-    s, width = shard_geometry(d, n_dev, cfg)
-    mesh = make_mesh((n_dev,), (axis,))
+    mesh = shard_mesh(devices, axis)
+    s, width = shard_geometry(d, mesh.size, cfg)
     u_pad = _pad_cols(u_stack, width)
 
     if cfg.compact_mode == "block":
@@ -431,10 +433,10 @@ def shard_compress_stack(u_stack: jax.Array, cfg, f, q_keys: jax.Array,
                 qq, keep_c, pos_c, cfg.block_size, cfg.capacity_frac))(q)
             return qb, res
 
-        run = shard_map(body, mesh=mesh,
-                        in_specs=(P(None, axis), P(), P(axis), P(axis)),
-                        out_specs=(P(None, axis), P(None, axis)),
-                        check_vma=False)
+        run = jax.shard_map(body, mesh=mesh,
+                            in_specs=(P(None, axis), P(), P(axis), P(axis)),
+                            out_specs=(P(None, axis), P(None, axis)),
+                            check_vma=False)
         q_bufs, residuals = run(u_pad, q_keys,
                                 _pad_cols(plan.keep_dense, width),
                                 _pad_cols(plan.pos, width))
@@ -451,10 +453,10 @@ def shard_compress_stack(u_stack: jax.Array, cfg, f, q_keys: jax.Array,
         qb = jnp.zeros((n, capacity), jnp.int32).at[:, slot_c].add(q)
         return jax.lax.psum(qb, axis), res
 
-    run = shard_map(body, mesh=mesh,
-                    in_specs=(P(None, axis), P(), P(axis), P(axis)),
-                    out_specs=(P(), P(None, axis)),
-                    check_vma=False)
+    run = jax.shard_map(body, mesh=mesh,
+                        in_specs=(P(None, axis), P(), P(axis), P(axis)),
+                        out_specs=(P(), P(None, axis)),
+                        check_vma=False)
     q_bufs, residuals = run(u_pad, q_keys, _pad_cols(plan.sel, width),
                             _pad_cols(plan.slot, width))
     return q_bufs, residuals[:, :d]

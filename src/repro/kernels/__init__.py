@@ -9,8 +9,9 @@ gather_quant     fused consensus select + quantize + residual (the whole
 flash_attention  VMEM-resident online-softmax attention (GQA/SWA) — the
                  TPU answer to the §Perf attention-tile traffic findings
 
-Each kernel has a pure-jnp oracle (ref.py / models.attention) and is
-validated in interpret mode on CPU; compiled path targets TPU VMEM tiles.
+Each kernel has a pure-jnp oracle (ref.py / models.attention).  Kernels
+compile on a TPU and interpret elsewhere (platform.py); the tests check
+them in interpret mode on the CPU and compile them for a described v5e.
 """
 
 from . import (bitpack, flash_attention, gather_quant, ops, ref,  # noqa: F401

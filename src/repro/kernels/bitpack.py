@@ -21,16 +21,27 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .platform import resolve_interpret
 from .ref import GROUP, LANES
 
 ROWS_PER_BLOCK = 8  # packed (uint32) rows produced per grid step
 
 
+def pack_rows(bits):
+    """(GROUP, LANES) 0/1 int32 -> (LANES,) int32 word bit pattern.
+
+    Mosaic reduces no unsigned integers, so the fold runs in int32: the
+    shifted rows hold disjoint bits, whose two's-complement sum is their
+    OR (bit 31 included), i.e. the uint32 word's bits exactly.  Callers
+    bitcast the int32 result to uint32 outside the kernel.
+    """
+    shifts = jax.lax.broadcasted_iota(jnp.int32, bits.shape, 0)
+    return (bits << shifts).sum(axis=0)
+
+
 def _pack_kernel(mask_ref, out_ref):
     for g in range(ROWS_PER_BLOCK):  # static unroll
-        rows = mask_ref[g * GROUP:(g + 1) * GROUP, :].astype(jnp.uint32)
-        shifts = jax.lax.broadcasted_iota(jnp.uint32, rows.shape, 0)
-        out_ref[g, :] = (rows << shifts).sum(axis=0).astype(jnp.uint32)
+        out_ref[g, :] = pack_rows(mask_ref[g * GROUP:(g + 1) * GROUP, :])
 
 
 def _unpack_kernel(words_ref, out_ref):
@@ -41,23 +52,24 @@ def _unpack_kernel(words_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def pack(mask: jax.Array, *, interpret: bool = True) -> jax.Array:
+def pack(mask: jax.Array, *, interpret: bool | None = None) -> jax.Array:
     """(R, LANES) 0/1 int -> (R//32, LANES) uint32.  R % (32*8) == 0."""
     r, l = mask.shape
     assert l == LANES and r % (GROUP * ROWS_PER_BLOCK) == 0, (r, l)
     grid = (r // (GROUP * ROWS_PER_BLOCK),)
-    return pl.pallas_call(
+    words = pl.pallas_call(
         _pack_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((GROUP * ROWS_PER_BLOCK, LANES), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((ROWS_PER_BLOCK, LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r // GROUP, LANES), jnp.uint32),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((r // GROUP, LANES), jnp.int32),
+        interpret=resolve_interpret(interpret),
     )(mask.astype(jnp.int32))
+    return jax.lax.bitcast_convert_type(words, jnp.uint32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def unpack(words: jax.Array, *, interpret: bool = True) -> jax.Array:
+def unpack(words: jax.Array, *, interpret: bool | None = None) -> jax.Array:
     """(G, LANES) uint32 -> (G*32, LANES) uint8."""
     g, l = words.shape
     assert l == LANES and g % ROWS_PER_BLOCK == 0, (g, l)
@@ -68,5 +80,5 @@ def unpack(words: jax.Array, *, interpret: bool = True) -> jax.Array:
         in_specs=[pl.BlockSpec((ROWS_PER_BLOCK, LANES), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((GROUP * ROWS_PER_BLOCK, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((g * GROUP, LANES), jnp.uint8),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(words)

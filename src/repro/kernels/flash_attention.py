@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .platform import resolve_interpret
+
 QB = 512
 KB = 512
 NEG_INF = -1e30
@@ -70,7 +72,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, kb: int, causal: bool,
 @functools.partial(jax.jit, static_argnames=("causal", "window", "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int = 0,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """q: (B, S, H, D); k, v: (B, T, Hk, D) with H % Hk == 0.
 
     Returns (B, S, H, Dv).  S % QB == 0 and T % KB == 0 required (the model
@@ -100,7 +102,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((1, 1, QB, dv), lambda bh, gi, qi: (bh, gi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b * hk, g, s, dv), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qg, kt, vt)
 
     return out.reshape(b, hk, g, s, dv).transpose(0, 3, 1, 2, 4).reshape(b, s, h, dv)

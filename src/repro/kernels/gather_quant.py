@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .platform import resolve_interpret
 from .ref import LANES
 
 BLOCK_ROWS = 8
@@ -48,7 +49,7 @@ def _gather_quant_kernel(f_ref, u_ref, uni_ref, sel_ref, q_ref, res_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def gather_quant(u: jax.Array, uniforms: jax.Array, sel: jax.Array,
-                 f: jax.Array, *, interpret: bool = True):
+                 f: jax.Array, *, interpret: bool | None = None):
     """(R, LANES) fp32 u, U[0,1) uniforms, 0/1 sel mask, scalar f ->
     ((R, LANES) int32 q, (R, LANES) fp32 residual) in one pass."""
     r, l = u.shape
@@ -63,5 +64,5 @@ def gather_quant(u: jax.Array, uniforms: jax.Array, sel: jax.Array,
         out_specs=(blk(), blk()),
         out_shape=(jax.ShapeDtypeStruct((r, LANES), jnp.int32),
                    jax.ShapeDtypeStruct((r, LANES), jnp.float32)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(f2, u.astype(jnp.float32), uniforms, sel.astype(jnp.int32))

@@ -1,9 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Handles the flat-vector <-> (rows, LANES) tiling, padding, and the
-CPU-interpret / TPU-compiled dispatch.  ``use_pallas=None`` auto-selects:
-compiled kernels on TPU, interpret mode elsewhere (this box is CPU-only, so
-interpret mode is the validation path; see DESIGN.md).
+Handles the flat-vector <-> (rows, LANES) tiling and padding.
+``interpret=None`` leaves the choice to the kernels
+(``platform.resolve_interpret``): compiled on a TPU, interpreted elsewhere.
 """
 
 from __future__ import annotations
@@ -15,10 +14,6 @@ from . import bitpack, gather_quant, ref, stoch_quant, vote_pack, vote_popcount
 from .ref import GROUP, LANES
 
 _TILE = GROUP * bitpack.ROWS_PER_BLOCK * LANES  # flat elements per pack grid step
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _to_rows(flat: jax.Array, multiple: int, pad_value=0):
@@ -33,14 +28,12 @@ def _to_rows(flat: jax.Array, multiple: int, pad_value=0):
 
 def pack_votes(mask_flat: jax.Array, *, interpret: bool | None = None) -> jax.Array:
     """Flat 0/1 votes (d,) -> packed uint32 (ceil-padded) words, flat."""
-    interpret = _interpret_default() if interpret is None else interpret
     m2, _ = _to_rows(mask_flat, GROUP * bitpack.ROWS_PER_BLOCK)
     return bitpack.pack(m2, interpret=interpret).reshape(-1)
 
 
 def unpack_votes(words_flat: jax.Array, d: int, *, interpret: bool | None = None) -> jax.Array:
     """Packed uint32 words (flat) -> 0/1 uint8 votes (d,)."""
-    interpret = _interpret_default() if interpret is None else interpret
     w2 = words_flat.reshape(-1, LANES)
     out = bitpack.unpack(w2, interpret=interpret).reshape(-1)
     return out[:d]
@@ -48,7 +41,6 @@ def unpack_votes(words_flat: jax.Array, d: int, *, interpret: bool | None = None
 
 def count_votes(words_stack_flat: jax.Array, d: int, *, interpret: bool | None = None) -> jax.Array:
     """(N, W) packed uint32 -> (d,) int32 vote counts (PS phase-1 reduce)."""
-    interpret = _interpret_default() if interpret is None else interpret
     n = words_stack_flat.shape[0]
     w3 = words_stack_flat.reshape(n, -1, LANES)
     out = vote_popcount.popcount_accum(w3, interpret=interpret).reshape(-1)
@@ -58,7 +50,6 @@ def count_votes(words_stack_flat: jax.Array, d: int, *, interpret: bool | None =
 def quantize_flat(u_flat: jax.Array, uniforms_flat: jax.Array, f,
                   *, interpret: bool | None = None) -> jax.Array:
     """Flat fp32 (d,) -> flat int32 (d,), Eq. 1 with scale f."""
-    interpret = _interpret_default() if interpret is None else interpret
     u2, d = _to_rows(u_flat, stoch_quant.BLOCK_ROWS)
     uni2, _ = _to_rows(uniforms_flat, stoch_quant.BLOCK_ROWS)
     out = stoch_quant.stoch_quant(u2, uni2, f, interpret=interpret)
@@ -70,7 +61,6 @@ def pack_votes_threshold(scores_flat: jax.Array, tau,
     """Fused phase-1 wire build: flat scores (d,) -> packed uint32 words of
     the mask ``scores >= tau``, with no intermediate d-sized vote array.
     Padding lanes get -inf so they can never vote."""
-    interpret = _interpret_default() if interpret is None else interpret
     s2, _ = _to_rows(scores_flat, GROUP * vote_pack.ROWS_PER_BLOCK,
                      pad_value=-jnp.inf)
     return vote_pack.vote_pack(s2, tau, interpret=interpret).reshape(-1)
@@ -98,7 +88,6 @@ def gather_quant_flat(u_flat: jax.Array, uniforms_flat: jax.Array,
                       *, interpret: bool | None = None):
     """Fused phase-2 client round: flat (u, uniforms, sel mask, f) ->
     (q_dense int32 (d,), residual fp32 (d,)) in one pass over u."""
-    interpret = _interpret_default() if interpret is None else interpret
     u2, d = _to_rows(u_flat, gather_quant.BLOCK_ROWS)
     uni2, _ = _to_rows(uniforms_flat, gather_quant.BLOCK_ROWS)
     sel2, _ = _to_rows(sel_flat, gather_quant.BLOCK_ROWS)

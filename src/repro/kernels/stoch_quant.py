@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .platform import resolve_interpret
 from .ref import LANES
 
 BLOCK_ROWS = 8
@@ -32,7 +33,7 @@ def _quant_kernel(f_ref, u_ref, uni_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def stoch_quant(u: jax.Array, uniforms: jax.Array, f: jax.Array,
-                *, interpret: bool = True) -> jax.Array:
+                *, interpret: bool | None = None) -> jax.Array:
     """(R, LANES) fp32, (R, LANES) U[0,1), scalar f -> (R, LANES) int32."""
     r, l = u.shape
     assert l == LANES and r % BLOCK_ROWS == 0, (r, l)
@@ -48,5 +49,5 @@ def stoch_quant(u: jax.Array, uniforms: jax.Array, f: jax.Array,
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, LANES), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(f2, u.astype(jnp.float32), uniforms)

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .bitpack import pack_rows
+from .platform import resolve_interpret
 from .ref import GROUP, LANES
 
 ROWS_PER_BLOCK = 8  # packed (uint32) rows produced per grid step
@@ -30,21 +32,20 @@ ROWS_PER_BLOCK = 8  # packed (uint32) rows produced per grid step
 def _vote_pack_kernel(tau_ref, score_ref, out_ref):
     tau = tau_ref[0, 0]
     for g in range(ROWS_PER_BLOCK):  # static unroll
-        rows = (score_ref[g * GROUP:(g + 1) * GROUP, :] >= tau).astype(jnp.uint32)
-        shifts = jax.lax.broadcasted_iota(jnp.uint32, rows.shape, 0)
-        out_ref[g, :] = (rows << shifts).sum(axis=0).astype(jnp.uint32)
+        rows = score_ref[g * GROUP:(g + 1) * GROUP, :] >= tau
+        out_ref[g, :] = pack_rows(rows.astype(jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def vote_pack(scores: jax.Array, tau: jax.Array, *,
-              interpret: bool = True) -> jax.Array:
+              interpret: bool | None = None) -> jax.Array:
     """(R, LANES) fp32 scores, scalar tau -> (R//32, LANES) uint32 words,
     bit r of word (g, l) holding ``scores[32 g + r, l] >= tau``."""
     r, l = scores.shape
     assert l == LANES and r % (GROUP * ROWS_PER_BLOCK) == 0, (r, l)
     grid = (r // (GROUP * ROWS_PER_BLOCK),)
     tau2 = jnp.asarray(tau, jnp.float32).reshape(1, 1)
-    return pl.pallas_call(
+    words = pl.pallas_call(
         _vote_pack_kernel,
         grid=grid,
         in_specs=[
@@ -52,6 +53,7 @@ def vote_pack(scores: jax.Array, tau: jax.Array, *,
             pl.BlockSpec((GROUP * ROWS_PER_BLOCK, LANES), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((ROWS_PER_BLOCK, LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r // GROUP, LANES), jnp.uint32),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((r // GROUP, LANES), jnp.int32),
+        interpret=resolve_interpret(interpret),
     )(tau2, scores.astype(jnp.float32))
+    return jax.lax.bitcast_convert_type(words, jnp.uint32)

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .platform import resolve_interpret
 from .ref import GROUP, LANES
 
 ROWS_PER_BLOCK = 8
@@ -34,16 +35,18 @@ ROWS_PER_BLOCK = 8
 def _popcount_kernel(words_ref, out_ref):
     w = words_ref[...]                         # (N, ROWS_PER_BLOCK, LANES)
     # bit-plane accumulation: per bit position r, the client-reduced plane
-    # is (ROWS_PER_BLOCK, LANES) — VPU shift/and/add only, no repeat.
-    planes = [((w >> jnp.uint32(r)) & jnp.uint32(1)).sum(axis=0)
-              .astype(jnp.int32)
+    # is (ROWS_PER_BLOCK, LANES) — VPU shift/and/add only, no repeat.  The
+    # words arrive bitcast to int32 (Mosaic reduces no unsigned ints); an
+    # arithmetic shift leaves bit r in place, so ``& 1`` reads it exactly.
+    planes = [((w >> r) & 1).sum(axis=0)
               for r in range(GROUP)]           # static unroll
     acc = jnp.stack(planes, axis=1)            # (ROWS, GROUP, LANES)
     out_ref[...] = acc.reshape(-1, acc.shape[-1])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def popcount_accum(words_stack: jax.Array, *, interpret: bool = True) -> jax.Array:
+def popcount_accum(words_stack: jax.Array, *,
+                   interpret: bool | None = None) -> jax.Array:
     """(N, G, LANES) uint32 packed votes -> (G*32, LANES) int32 counts."""
     n, g, l = words_stack.shape
     assert l == LANES and g % ROWS_PER_BLOCK == 0, (n, g, l)
@@ -54,5 +57,5 @@ def popcount_accum(words_stack: jax.Array, *, interpret: bool = True) -> jax.Arr
         in_specs=[pl.BlockSpec((n, ROWS_PER_BLOCK, LANES), lambda i: (0, i, 0))],
         out_specs=pl.BlockSpec((GROUP * ROWS_PER_BLOCK, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((g * GROUP, LANES), jnp.int32),
-        interpret=interpret,
-    )(words_stack)
+        interpret=resolve_interpret(interpret),
+    )(jax.lax.bitcast_convert_type(words_stack, jnp.int32))

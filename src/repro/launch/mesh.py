@@ -20,18 +20,24 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_test_mesh(*, multi_pod: bool = False, devices=None):
-    """Reduced mesh over however many (fake) devices the test session has."""
-    devices = jax.devices() if devices is None else devices
+    """Reduced mesh over ``devices`` (default: every visible device).
+
+    One device gives a ``(1, 1)`` (data, model) mesh: a single client
+    with no model parallelism.
+    """
+    devices = list(jax.devices() if devices is None else devices)
     n = len(devices)
     if multi_pod:
         assert n % 2 == 0 and n >= 8, n
         shape = (2, 2, n // 4)
         axes = ("pod", "data", "model")
+    elif n == 1:
+        shape, axes = (1, 1), ("data", "model")
     else:
         assert n % 2 == 0, n
         shape = (2, n // 2)
         axes = ("data", "model")
-    return make_mesh(shape, axes)
+    return make_mesh(shape, axes, devices=devices)
 
 
 # v5e hardware constants for the roofline (per chip / per link)

@@ -239,6 +239,7 @@ def make_async_packet_core(cfg: FediACConfig, net: AsyncConfig,
     tests consume.
     """
     spec = engines.resolve(cfg)
+    cfg = engines.with_pallas(cfg, spec)   # the spec's Pallas choice
     n = int(n_clients)
     stream = spec.name == "stream"
     sharded = spec.name == "sharded"

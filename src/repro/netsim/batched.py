@@ -150,6 +150,7 @@ def make_fediac_packet_core(cfg: FediACConfig, net: NetConfig,
     the Python wrapper prices the round from.
     """
     spec = engines.resolve(cfg)
+    cfg = engines.with_pallas(cfg, spec)   # the spec's Pallas choice
     n = int(n_clients)
     stream = spec.name == "stream"
     sharded = spec.name == "sharded"

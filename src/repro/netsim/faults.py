@@ -278,6 +278,7 @@ def make_chaos_packet_core(cfg: FediACConfig, net: FaultConfig,
     ``overflow_slots`` / ``aborted`` / ``attempts``.
     """
     spec = engines.resolve(cfg)
+    cfg = engines.with_pallas(cfg, spec)   # the spec's Pallas choice
     n = int(n_clients)
     stream = spec.name == "stream"
     sharded = spec.name == "sharded"

@@ -140,9 +140,9 @@ class JaxProfiler:
 
 
 class profiler_trace:
-    """Optional ``jax.profiler`` trace: a context manager that starts a
-    device trace into ``trace_dir`` when the profiler is available and
-    degrades to a no-op when it is not (or when ``trace_dir`` is None).
+    """``jax.profiler`` trace into ``trace_dir``: a context manager that
+    is a no-op when ``trace_dir`` is None.  A trace that was asked for and
+    cannot start or stop raises — a missing device trace is never silent.
 
     View the output with TensorBoard's profile plugin or Perfetto.
     """
@@ -153,20 +153,14 @@ class profiler_trace:
 
     def __enter__(self):
         if self.trace_dir:
-            try:
-                import jax
-                jax.profiler.start_trace(self.trace_dir)
-                self._active = True
-            except Exception:
-                self._active = False
+            import jax
+            jax.profiler.start_trace(self.trace_dir)
+            self._active = True
         return self
 
     def __exit__(self, *exc):
         if self._active:
-            try:
-                import jax
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
+            import jax
             self._active = False
+            jax.profiler.stop_trace()
         return False

@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from jax.flatten_util import ravel_pytree
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.fediac import dense_allreduce, fediac_allreduce
 from repro.models import loss_fn, param_specs
 from repro.models.model import init_params
@@ -268,10 +267,10 @@ def _make_fl_step(cfg, mesh, pspec, res_spec, axes, n_clients, lr):
                                         unravel(new_res))
             return unravel(mean), nr
 
-        return shard_map(local, mesh=mesh,
-                         in_specs=(ustack_spec, res_spec, P()),
-                         out_specs=(pspec, res_spec),
-                         check_vma=False)(u_stack, res_stack, key)
+        return jax.shard_map(local, mesh=mesh,
+                             in_specs=(ustack_spec, res_spec, P()),
+                             out_specs=(pspec, res_spec),
+                             check_vma=False)(u_stack, res_stack, key)
 
     def step(params, residual, batch, key):
         gb = batch["tokens"].shape[0]

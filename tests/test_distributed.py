@@ -31,9 +31,10 @@ def test_fediac_allreduce_on_mesh():
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
+from repro.compat import make_mesh
 from repro.core.fediac import FediACConfig, fediac_allreduce
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = FediACConfig(k_frac=0.1, bits=12, capacity_frac=0.1)
 d = 1024
 u = jax.random.normal(jax.random.PRNGKey(0), (4, d)) ** 3
@@ -124,10 +125,11 @@ def test_mesh_baselines_and_packed_votes():
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
+from repro.compat import make_mesh
 from repro.core.fediac import FediACConfig, fediac_allreduce
 from repro.core.mesh_baselines import switchml_allreduce, topk_allreduce
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 d = 262144
 u = jax.random.normal(jax.random.PRNGKey(0), (4, d)) ** 3
 res = jnp.zeros((4, d))
