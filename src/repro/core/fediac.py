@@ -37,6 +37,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
+from repro.obs.scopes import scope
 from repro.validate import (check_at_least, check_choice, check_interval,
                             require)
 
@@ -372,37 +373,40 @@ def aggregate_stack(u_stack: jax.Array, cfg: FediACConfig, key: jax.Array,
     compiled program; see :func:`repro.core.round_plan.build_round_plan`).
     """
     n, d = u_stack.shape
-    keys = jax.random.split(key, 2 * n)
-    vote_keys, q_keys = keys[:n], keys[n:]
-    # Phase 1: every client votes; the PS sums 0/1 arrays.
-    counts = _vote_counts_stack(u_stack, cfg, vote_keys)
-    # Scale factor from the global max magnitude (SwitchML-style).
-    m = jnp.max(jnp.abs(u_stack))
-    f = scale_factor(cfg.bits, n, 1.0) / jnp.clip(m, 1e-12, None)
-    # Phase 2: the consensus plan is built ONCE from the shared counts and
-    # passed into every client's compress (the round-plan engine) — never
-    # recomputed inside the vmap.
-    plan = build_round_plan(counts, cfg, n, a=a,
-                            with_dense_mask=plan_wants_dense_mask(cfg))
-    if cfg.compact_mode == "block":
-        # dense form: summing the per-client compact buffers and scattering
-        # the sum back equals masking the dense integer sum — skip the
-        # d-sized compact scatter per client (wire paths keep it).
-        q_dense, residuals = jax.vmap(
-            lambda u, k: _block_compress_dense(u, cfg, f, k, plan))(u_stack,
-                                                                    q_keys)
-        # the PS's pipelined integer addition (or its §18 order-statistic
-        # close — robust_agg.client_sum Python-gates on "sum")
-        summed, kept = robust_agg.client_sum(q_dense, cfg)
-        delta = jnp.where(plan.keep_dense, summed,
-                          0).astype(jnp.float32) / (kept * f)
-        return delta, residuals, counts, round_traffic(cfg, d)
-    compress = phase2_compress(cfg)
-    q_bufs, residuals = jax.vmap(
-        lambda u, k: compress(u, cfg, f, k, plan))(u_stack, q_keys)
-    # the PS's pipelined integer addition (or the §18 trimmed close)
-    summed, kept = robust_agg.client_sum(q_bufs, cfg)
-    delta = scatter_sum(summed, plan.idx, plan.keep, cfg, d).astype(jnp.float32) / (kept * f)
+    with scope("vote"):
+        keys = jax.random.split(key, 2 * n)
+        vote_keys, q_keys = keys[:n], keys[n:]
+        # Phase 1: every client votes; the PS sums 0/1 arrays.
+        counts = _vote_counts_stack(u_stack, cfg, vote_keys)
+        # the global max magnitude, for the scale factor (SwitchML-style)
+        m = jnp.max(jnp.abs(u_stack))
+    with scope("consensus"):
+        f = scale_factor(cfg.bits, n, 1.0) / jnp.clip(m, 1e-12, None)
+        # the consensus plan is built ONCE from the shared counts and
+        # passed into every client's compress (the round-plan engine) —
+        # never recomputed inside the vmap.
+        plan = build_round_plan(counts, cfg, n, a=a,
+                                with_dense_mask=plan_wants_dense_mask(cfg))
+    with scope("phase2"):
+        if cfg.compact_mode == "block":
+            # dense form: summing the per-client compact buffers and scattering
+            # the sum back equals masking the dense integer sum — skip the
+            # d-sized compact scatter per client (wire paths keep it).
+            q_dense, residuals = jax.vmap(
+                lambda u, k: _block_compress_dense(u, cfg, f, k, plan))(u_stack,
+                                                                        q_keys)
+            # the PS's pipelined integer addition (or its §18 order-statistic
+            # close — robust_agg.client_sum Python-gates on "sum")
+            summed, kept = robust_agg.client_sum(q_dense, cfg)
+            delta = jnp.where(plan.keep_dense, summed,
+                              0).astype(jnp.float32) / (kept * f)
+            return delta, residuals, counts, round_traffic(cfg, d)
+        compress = phase2_compress(cfg)
+        q_bufs, residuals = jax.vmap(
+            lambda u, k: compress(u, cfg, f, k, plan))(u_stack, q_keys)
+        # the PS's pipelined integer addition (or the §18 trimmed close)
+        summed, kept = robust_agg.client_sum(q_bufs, cfg)
+        delta = scatter_sum(summed, plan.idx, plan.keep, cfg, d).astype(jnp.float32) / (kept * f)
     return delta, residuals, counts, round_traffic(cfg, d)
 
 

@@ -37,6 +37,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.obs.scopes import scope
+
 __all__ = ["ROBUST_AGG_MODES", "trim_count", "trimmed_sum", "client_sum",
            "kept_count"]
 
@@ -100,16 +102,17 @@ def client_sum(q: jax.Array, cfg):
     The single seam every in-memory engine sums through (monolithic,
     stream, sharded — ``engines.py`` dispatch): ``q`` is ``[N, chunk]``
     with the *full* client axis present, so the coordinate-wise trim is
-    chunk-local.  Returns ``(aggregated [chunk], kept)`` where ``kept``
-    is the Python int ``N`` in sum mode (the call site's ``/(n * f)``
-    denominator is the pre-robust expression, bitwise) and a traced
-    int32 scalar otherwise.
+    chunk-local; it runs in the ``register_fold`` device scope.  Returns
+    ``(aggregated [chunk], kept)`` where ``kept`` is the Python int ``N``
+    in sum mode (the call site's ``/(n * f)`` denominator is the
+    pre-robust expression, bitwise) and a traced int32 scalar otherwise.
     """
     n = q.shape[0]
-    if cfg.robust_agg == "sum":
-        return q.sum(axis=0), n
-    t = trim_count(cfg.robust_agg, cfg.trim_frac, n)
-    return trimmed_sum(q, jnp.ones((n,), bool), t)
+    with scope("register_fold"):
+        if cfg.robust_agg == "sum":
+            return q.sum(axis=0), n
+        t = trim_count(cfg.robust_agg, cfg.trim_frac, n)
+        return trimmed_sum(q, jnp.ones((n,), bool), t)
 
 
 def kept_count(cfg, n: int):

@@ -57,6 +57,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.compat import make_mesh
+from repro.obs.scopes import scope
 
 from . import compaction, robust_agg, voting
 from .quantize import dequantize, quantize, scale_factor
@@ -330,8 +331,9 @@ def aggregate_shard(u_stack: jax.Array, cfg, key: jax.Array, *, a=None,
     _check_shardable(cfg)
     mesh = shard_mesh(devices, axis)
     s, width = shard_geometry(d, mesh.size, cfg)
-    keys = jax.random.split(key, 2 * n)
-    vote_keys, q_keys = keys[:n], keys[n:]
+    with scope("vote"):
+        keys = jax.random.split(key, 2 * n)
+        vote_keys, q_keys = keys[:n], keys[n:]
     k = min(cfg.k(d), d)
     capacity = cfg.capacity(d)
     a_arr = jnp.asarray(cfg.threshold(n) if a is None else a, jnp.int32)
@@ -341,21 +343,30 @@ def aggregate_shard(u_stack: jax.Array, cfg, key: jax.Array, *, a=None,
         start = me * s
         gidx = start + jnp.arange(s, dtype=jnp.int32)
         valid = gidx < d
-        counts_loc, m = _phase1_counts(u_loc, cfg, vks, k, d, start, valid,
-                                       axis)
-        f = scale_factor(cfg.bits, n, 1.0) / jnp.clip(m, 1e-12, None)
-        if cfg.compact_mode == "block":
-            if getattr(cfg, "consensus_floor", 0) > 0:
-                hist_loc = jnp.zeros((n + 1,), jnp.int32).at[counts_loc].add(
-                    valid.astype(jnp.int32))
-                garr = _suffix_counts(jax.lax.psum(hist_loc, axis))
+        with scope("vote"):
+            counts_loc, m = _phase1_counts(u_loc, cfg, vks, k, d, start,
+                                           valid, axis)
+        with scope("consensus"):
+            f = scale_factor(cfg.bits, n, 1.0) / jnp.clip(m, 1e-12, None)
+            if cfg.compact_mode == "block":
+                if getattr(cfg, "consensus_floor", 0) > 0:
+                    hist_loc = jnp.zeros((n + 1,), jnp.int32).at[
+                        counts_loc].add(valid.astype(jnp.int32))
+                    garr = _suffix_counts(jax.lax.psum(hist_loc, axis))
+                else:
+                    garr = None
+                a_eff = _floored_threshold(cfg, a_in, garr, n)
+                keep_b, _ = compaction.block_select(counts_loc, a_eff,
+                                                    cfg.block_size,
+                                                    cfg.capacity_frac)
+                keep_b = keep_b & valid
             else:
-                garr = None
-            a_eff = _floored_threshold(cfg, a_in, garr, n)
-            keep_b, _ = compaction.block_select(counts_loc, a_eff,
-                                                cfg.block_size,
-                                                cfg.capacity_frac)
-            keep_b = keep_b & valid
+                sel, slot, garr = _consensus_shards(counts_loc, valid, n,
+                                                    capacity, me, axis)
+                a_eff = _floored_threshold(cfg, a_in, garr, n)
+                keep_f = (sel & (counts_loc >= a_eff)).astype(jnp.float32)
+
+        if cfg.compact_mode == "block":
             bs = int(cfg.block_size)
             cs = min(s, -(-_PHASE2_CHUNK // bs) * bs)
 
@@ -369,10 +380,6 @@ def aggregate_shard(u_stack: jax.Array, cfg, key: jax.Array, *, a=None,
                 return dc, rc
 
         else:
-            sel, slot, garr = _consensus_shards(counts_loc, valid, n,
-                                                capacity, me, axis)
-            a_eff = _floored_threshold(cfg, a_in, garr, n)
-            keep_f = (sel & (counts_loc >= a_eff)).astype(jnp.float32)
             cs = min(s, _PHASE2_CHUNK)
 
             def p2(uc, st):
@@ -385,10 +392,11 @@ def aggregate_shard(u_stack: jax.Array, cfg, key: jax.Array, *, a=None,
                       .astype(jnp.int32)).astype(jnp.float32) / (kept * f)
                 return dc, rc
 
-        if s <= cs:
-            delta_loc, res = p2(u_loc, jnp.int32(0))
-        else:
-            delta_loc, res = _phase2_chunked(u_loc, p2, cs)
+        with scope("phase2"):
+            if s <= cs:
+                delta_loc, res = p2(u_loc, jnp.int32(0))
+            else:
+                delta_loc, res = _phase2_chunked(u_loc, p2, cs)
         return delta_loc, res, counts_loc
 
     run = jax.shard_map(body, mesh=mesh,

@@ -48,6 +48,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.obs.scopes import scope
+
 from . import compaction, robust_agg, selection, voting
 from .quantize import dequantize, quantize, scale_factor
 from .round_plan import RoundPlan, build_round_plan
@@ -277,20 +279,25 @@ def aggregate_stream(u_stack: jax.Array, cfg, key: jax.Array, *, a=None,
     n, d = u_stack.shape
     _check_streamable(cfg)
     chunk = _chunk_size(cfg, d, chunk)
-    keys = jax.random.split(key, 2 * n)
-    vote_keys, q_keys = keys[:n], keys[n:]
-    if cfg.vote_mode == "threshold":
-        counts, m = _phase1_threshold(u_stack, cfg, chunk)
-    else:
-        counts, m = _phase1_topk(u_stack, cfg, vote_keys)
-    f = scale_factor(cfg.bits, n, 1.0) / jnp.clip(m, 1e-12, None)
+    with scope("vote"):
+        keys = jax.random.split(key, 2 * n)
+        vote_keys, q_keys = keys[:n], keys[n:]
+        if cfg.vote_mode == "threshold":
+            counts, m = _phase1_threshold(u_stack, cfg, chunk)
+        else:
+            counts, m = _phase1_topk(u_stack, cfg, vote_keys)
     topk = cfg.compact_mode != "block"
-    plan = build_round_plan(counts, cfg, n, a=a, with_dense_mask=topk,
-                            with_slot_map=topk)
-    if topk:
-        delta, residuals = _phase2_topk(u_stack, cfg, f, q_keys, plan, chunk)
-    else:
-        delta, residuals = _phase2_block(u_stack, cfg, f, q_keys, plan, chunk)
+    with scope("consensus"):
+        f = scale_factor(cfg.bits, n, 1.0) / jnp.clip(m, 1e-12, None)
+        plan = build_round_plan(counts, cfg, n, a=a, with_dense_mask=topk,
+                                with_slot_map=topk)
+    with scope("phase2"):
+        if topk:
+            delta, residuals = _phase2_topk(u_stack, cfg, f, q_keys, plan,
+                                            chunk)
+        else:
+            delta, residuals = _phase2_block(u_stack, cfg, f, q_keys, plan,
+                                             chunk)
     return delta, residuals, counts, round_traffic(cfg, d)
 
 

@@ -16,7 +16,8 @@ from .trace import (SCHEMA_VERSION, Tracer, chrome_trace, load_trace,
                     validate_records, validate_trace, write_chrome_trace)
 from .probe import (NULL_PROBE, NullProbe, RecordingProbe, RoundProbe,
                     as_probe)
-from .jaxprof import JaxProfiler, JitEntry, profiler_trace
+from .jaxprof import JaxProfiler, JitEntry
+from .scopes import SCOPES, scope
 from .report import render_markdown, render_report, round_rows
 
 __all__ = [
@@ -25,6 +26,6 @@ __all__ = [
     "SCHEMA_VERSION", "Tracer", "chrome_trace", "load_trace",
     "validate_records", "validate_trace", "write_chrome_trace",
     "NULL_PROBE", "NullProbe", "RecordingProbe", "RoundProbe", "as_probe",
-    "JaxProfiler", "JitEntry", "profiler_trace",
+    "JaxProfiler", "JitEntry", "SCOPES", "scope",
     "render_markdown", "render_report", "round_rows",
 ]

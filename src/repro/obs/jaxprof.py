@@ -1,6 +1,6 @@
 """Profiling hooks for jit entries: compile-vs-execute wall-clock split,
-compile-cache hit counting, a donation audit, and an optional
-``jax.profiler`` trace directory (DESIGN.md §15).
+compile-cache hit counting and a donation audit (DESIGN.md §15). A
+device trace is captured with ``jax.profiler.trace``.
 
 ``JaxProfiler.wrap`` turns a jitted callable into a counted one:
 
@@ -26,7 +26,7 @@ import time
 import warnings
 from dataclasses import dataclass, field
 
-__all__ = ["JitEntry", "JaxProfiler", "profiler_trace"]
+__all__ = ["JitEntry", "JaxProfiler"]
 
 _DONATION_MARKERS = ("donat",)   # matches jax's donation warning family
 
@@ -137,30 +137,3 @@ class JaxProfiler:
         return [(e.name, e.calls, e.compiles, e.compile_wall_s,
                  e.execute_wall_s, e.donation_warnings)
                 for e in sorted(self.entries.values(), key=lambda x: x.name)]
-
-
-class profiler_trace:
-    """``jax.profiler`` trace into ``trace_dir``: a context manager that
-    is a no-op when ``trace_dir`` is None.  A trace that was asked for and
-    cannot start or stop raises — a missing device trace is never silent.
-
-    View the output with TensorBoard's profile plugin or Perfetto.
-    """
-
-    def __init__(self, trace_dir: str | None):
-        self.trace_dir = trace_dir
-        self._active = False
-
-    def __enter__(self):
-        if self.trace_dir:
-            import jax
-            jax.profiler.start_trace(self.trace_dir)
-            self._active = True
-        return self
-
-    def __exit__(self, *exc):
-        if self._active:
-            import jax
-            self._active = False
-            jax.profiler.stop_trace()
-        return False

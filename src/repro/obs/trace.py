@@ -22,7 +22,10 @@ Record types (see :data:`SCHEMA_VERSION` / :func:`validate_records`):
 
 The tracer is deliberately plain host-side Python (json + file I/O): it
 can never enter a traced program, so instrumented runs stay bit-identical
-(§15 no-perturbation rule).
+(§15 no-perturbation rule). A host span also opens a
+``jax.profiler.TraceAnnotation`` of its name, so a device trace captured
+with ``jax.profiler.trace`` holds the span beside the device ops; with
+no trace running the annotation records nothing.
 """
 
 from __future__ import annotations
@@ -30,6 +33,8 @@ from __future__ import annotations
 import json
 import time
 from dataclasses import dataclass, field
+
+import jax
 
 __all__ = ["SCHEMA_VERSION", "Span", "Tracer", "load_trace",
            "validate_records", "validate_trace", "chrome_trace",
@@ -83,7 +88,8 @@ class Tracer:
 
     # ------------------------------------------------------------------
     def span(self, name: str, *, round: int | None = None, **attrs):
-        """Context manager: a host-clock span around a ``with`` body."""
+        """Context manager: a host-clock span around a ``with`` body, also
+        a profiler annotation of the same name."""
         return _SpanCM(self, name, round, attrs)
 
     def begin(self, name: str, *, round: int | None = None, **attrs) -> Span:
@@ -134,7 +140,7 @@ class Tracer:
 
 
 class _SpanCM:
-    __slots__ = ("_tr", "_name", "_round", "_attrs", "_sp")
+    __slots__ = ("_tr", "_name", "_round", "_attrs", "_sp", "_ann")
 
     def __init__(self, tr, name, round_, attrs):
         self._tr, self._name, self._round, self._attrs = \
@@ -143,9 +149,12 @@ class _SpanCM:
     def __enter__(self):
         self._sp = self._tr.begin(self._name, round=self._round,
                                   **self._attrs)
+        self._ann = jax.profiler.TraceAnnotation(self._name)
+        self._ann.__enter__()
         return self._sp
 
     def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
         self._tr.end(self._sp)
         return False
 

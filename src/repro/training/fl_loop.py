@@ -24,6 +24,7 @@ from repro.core.fediac import FediACConfig
 from repro.validate import (check_at_least, check_choice,
                             check_finite_at_least, check_positive_finite)
 from repro.obs.probe import as_probe
+from repro.obs.scopes import scope
 from repro.switch import SwitchProfile, client_rates, n_packets, round_wall_clock
 
 
@@ -328,9 +329,11 @@ def run_federated(clients, test, flcfg: FLConfig, *, hidden=(128, 64),
                                **agg_kwargs)
 
     client_round = make_client_round(unravel, flcfg.batch, flcfg.local_steps)
-    local_round = jax.jit(
-        lambda flat_params, key, lr: client_round(flat_params, key, lr,
-                                                  cx, cy, size))
+    def local_train(flat_params, key, lr):
+        with scope("local_train"):
+            return client_round(flat_params, key, lr, cx, cy, size)
+
+    local_round = jax.jit(local_train)
     # host-side observation only: wrap_jit counts compiles/cache hits
     # around the same jitted callables (NullProbe returns them unchanged),
     # and transports with probe support report their stats dicts.

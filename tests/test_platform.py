@@ -1,7 +1,7 @@
 """What decides where the program runs and what it keeps: the kernels'
 interpret switch, the one-device mesh, the compile cache's directory, and
-the failures that must not pass silently (a requested device trace, a
-benchmark section that raised)."""
+the failures that must not pass silently (a benchmark section that
+raised)."""
 
 from __future__ import annotations
 
@@ -13,7 +13,6 @@ import pytest
 from repro.kernels.platform import resolve_interpret
 from repro.launch import cache
 from repro.launch.mesh import make_test_mesh
-from repro.obs import profiler_trace
 
 
 def test_interpret_resolves_from_the_backend():
@@ -47,17 +46,6 @@ def test_compile_cache_env_left_to_jax(monkeypatch, cache_dir_restored):
     jax.config.update("jax_compilation_cache_dir", None)
     assert cache.enable_compile_cache() == "/elsewhere"
     assert jax.config.jax_compilation_cache_dir is None
-
-
-def test_profiler_trace_raises_when_it_cannot_start(monkeypatch):
-    def refuse(_):
-        raise RuntimeError("no profiler")
-    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
-    with pytest.raises(RuntimeError, match="no profiler"):
-        with profiler_trace("trace-dir"):
-            pass
-    with profiler_trace(None):   # no directory: nothing is traced
-        pass
 
 
 def test_benchmark_run_exits_nonzero_on_a_failed_section(monkeypatch,
