@@ -4,9 +4,11 @@
 temporaries per round — Gumbel score stacks, vote masks, dense quantization
 buffers — which caps the round size this box can hold.  This module
 restructures the same round as **chunk scans**: ``lax.scan`` over
-``stream_chunk``-sized coordinate ranges whose carries (the residual stack,
-the dense quantized sum) are updated in place by XLA's scan donation, so
-the live set beyond inputs/outputs is O(N·chunk) + O(d).
+``stream_chunk``-sized coordinate ranges whose carries are updated in
+place, so the live set beyond inputs/outputs is O(N·chunk) + O(d).  The
+block-compact phase 2 writes the residual over the (donated) input stack
+itself and the delta into a flat (d,) carry; the topk-compact phase 2
+keeps write-only carries (see :func:`_phase2_topk`).
 
 Exactness (DESIGN.md §12) rests on three facts:
 
@@ -192,10 +194,14 @@ def _phase2_topk(u_stack, cfg, f, q_keys, plan: RoundPlan, chunk: int):
     """Streamed topk-compact phase 2 for the in-memory engine: chunks are
     read from the (loop-invariant) input stack and written into
     **write-only** carries — the residual stack and the dense int32
-    quantized-sum.  Write-only matters: a carry that is also sliced as the
-    chunk source is a read-modify-write XLA:CPU double-buffers on every
-    scan step (a hidden O(N·d) copy per chunk).  The compact buffer is a
-    C-sized gather at the end — no d-sized scatters anywhere."""
+    quantized-sum.  The compact buffer is a C-sized gather at the end — no
+    d-sized scatters anywhere.
+
+    Unlike :func:`_phase2_block` this path keeps a second [N, d] residual
+    stack: a carry that is also sliced as the chunk source is one the TPU
+    compiler updates in place, but XLA:CPU copies it whole on every scan
+    step, and no measured configuration runs topk compaction on the chip
+    to show which way pays off here."""
     n, d = u_stack.shape
     uq_all = None
     if not _fused(cfg):
@@ -229,13 +235,24 @@ def _phase2_block(u_stack, cfg, f, q_keys, plan: RoundPlan, chunk: int):
     the whole round is chunk-local, and the compact/scatter round-trip
     collapses to ``where(keep, sum_i q_i, 0)`` per chunk (what
     ``block_scatter(sum block_compact(q_i))`` computes coordinate-wise).
-    The residual carry is write-only (chunks read from the invariant
-    input), so XLA updates it in place instead of double-buffering."""
+
+    Both results are written in place.  The scan carries the input stack
+    itself: each step reads its chunk from the carry and writes the
+    chunk's residual back over it at the same offset, so under the
+    caller's ``donate_argnums=0`` the residual output *is* the donated
+    buffer — no second [N, d] stack, zero fill or final copy.  The delta
+    rides in the carry as a flat (d,) vector written chunk by chunk, not
+    as a stacked per-chunk scan output that has to be relaid and
+    concatenated afterwards.  Each chunk is read before it is written and
+    no step reads another's range, so the results are those of reading
+    the invariant input.  The TPU compiler keeps the carry in place;
+    XLA:CPU copies it on every scan step, a cost of the CPU backend only."""
     n, d = u_stack.shape
     dt = u_stack.dtype
 
-    def body(resid, start, size):
-        u_c = jax.lax.dynamic_slice(u_stack, (0, start), (n, size))
+    def body(carry, start, size):
+        resid, delta = carry
+        u_c = jax.lax.dynamic_slice(resid, (0, start), (n, size))
         keep_c = jax.lax.dynamic_slice(plan.keep_dense, (start,), (size,))
         uni = jax.vmap(lambda kk: uniform_block(kk, start, size, d))(q_keys)
         q = quantize(jnp.where(keep_c, u_c, 0.0), f, uni)
@@ -244,10 +261,12 @@ def _phase2_block(u_stack, cfg, f, q_keys, plan: RoundPlan, chunk: int):
         delta_c = jnp.where(keep_c, qagg,
                             0).astype(jnp.float32) / (kept * f)
         resid = jax.lax.dynamic_update_slice(resid, res, (0, start))
-        return resid, delta_c
+        delta = jax.lax.dynamic_update_slice(delta, delta_c, (start,))
+        return (resid, delta), None
 
-    residuals, ys, yt = _scan_chunks(body, jnp.zeros_like(u_stack), d, chunk)
-    return _cat_coords(ys, yt), residuals
+    (residuals, delta), _, _ = _scan_chunks(
+        body, (u_stack, jnp.zeros((d,), jnp.float32)), d, chunk)
+    return delta, residuals
 
 
 # ---------------------------------------------------------------------------

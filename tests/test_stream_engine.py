@@ -238,21 +238,32 @@ def test_fleet_runs_streaming_engine():
 # buffer donation
 # ---------------------------------------------------------------------------
 
-def test_aggregate_stream_donates_input_stack():
+@pytest.mark.parametrize("vote_mode,compact_mode", MODES)
+def test_aggregate_stream_donates_input_stack(vote_mode, compact_mode):
     """Under jit(donate_argnums=(0,)) the u_stack buffer is consumed —
-    and no copy-on-donate warning fires (donation is actually usable)."""
-    cfg = FediACConfig(stream_chunk=1000)
-    u = _u(4, 4096)
-    ref = aggregate_stream(u, cfg, KEY)
-    fn = jax.jit(lambda u, k: aggregate_stream(u, cfg, k)[:3],
+    no copy-on-donate warning fires (donation is actually usable) — and
+    the outputs equal the undonated call's and the monolithic engine's
+    bit for bit.  d = 5000 is four 1024-wide chunks plus a tail, so the
+    block path's in-place residual and flat delta writes cover the scan
+    steps and the trailing call."""
+    cfg = FediACConfig(vote_mode=vote_mode, compact_mode=compact_mode,
+                       block_size=256)
+    u = _u(4, 5000)
+    mono = aggregate_stack(u, cfg, KEY)
+    ref = aggregate_stream(u, cfg, KEY, chunk=1024)
+    fn = jax.jit(lambda u, k: aggregate_stream(u, cfg, k, chunk=1024)[:3],
                  donate_argnums=(0,))
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
         out = jax.block_until_ready(fn(u, KEY))
     assert not [w for w in caught if "donat" in str(w.message).lower()]
     assert u.is_deleted()
-    for x, y in zip(ref[:3], out):
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    for name, x, y, z in zip(("delta", "residuals", "counts"), ref[:3], out,
+                             mono[:3]):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
+                                      err_msg=name)
+        np.testing.assert_array_equal(np.asarray(z), np.asarray(y),
+                                      err_msg=name)
 
 
 def test_fl_loop_carry_in_donates():

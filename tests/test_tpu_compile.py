@@ -1,5 +1,6 @@
 """The six kernel entry points of ``repro.kernels.ops`` compile for a TPU
-v5e at real width (d = 2^24, N = 32 for the popcount).
+v5e at real width (d = 2^24, N = 32 for the popcount), and the stream
+engine's block-mode round compiles with phase 2 writing in place.
 
 The chip is described, not attached: the TPU compiler runs on this host
 and refuses what the chip's compiler would refuse (an unsigned reduction,
@@ -13,6 +14,7 @@ library.
 from __future__ import annotations
 
 import os
+import re
 from functools import partial
 
 import jax
@@ -20,6 +22,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.fediac import FediACConfig
+from repro.core.stream_engine import aggregate_stream
 from repro.kernels import ops
 
 D = 2 ** 24
@@ -67,3 +71,25 @@ def test_kernel_compiles_for_v5e(one_chip, name):
             for s, dt in shapes]
     compiled = jax.jit(partial(fn, interpret=False)).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_stream_round_phase2_writes_in_place_for_v5e(one_chip):
+    """The block-mode stream round, input donated, keeps no second
+    ``[N, d]`` stack: phase 2 writes the residual over the donated input
+    and the delta into a flat (d,) carry.  A zeroed residual carry, or a
+    copy of it into the output, shows as an ``f32[N, d]`` broadcast or
+    copy in the optimized program and as an ``[N, d]`` temporary."""
+    n, d = 8, 2 ** 22 + 3 * 4096   # 16 chunks and a tail
+    cfg = FediACConfig(vote_mode="threshold", compact_mode="block",
+                       block_size=4096, alpha=-0.2)
+    fn = jax.jit(lambda u, k: aggregate_stream(u, cfg, k, chunk=2 ** 18)[:3],
+                 donate_argnums=0)
+    u = jax.ShapeDtypeStruct((n, d), jnp.float32, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    compiled = fn.lower(u, k).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < n * d * 4 // 4, temp
+    stack_ops = re.findall(
+        rf"= f32\[{n},{d}\]\{{[^}}]*\}} (copy|broadcast)\(",
+        compiled.as_text())
+    assert not stack_ops, stack_ops
