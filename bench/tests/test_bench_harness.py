@@ -68,39 +68,15 @@ def test_config_d_is_the_registry_models_parameter_count(conf):
     assert arch.vocab == data["vocab"]
 
 
-def _mamba2_count(c):
-    """Mamba-2 (state-spaces/mamba2-130m): embedding tied to the head."""
-    inner = c["ssm_expand"] * c["d_model"]
-    conv_dim = inner + 2 * c["ssm_groups"] * c["ssm_state"]
-    layer = (c["d_model"] * (inner + conv_dim + c["ssm_heads"])   # in_proj
-             + conv_dim * (c["conv_width"] + 1)                    # conv1d, bias
-             + 3 * c["ssm_heads"]                                  # dt_bias, A_log, D
-             + inner                                               # gated norm
-             + inner * c["d_model"]                                # out_proj
-             + c["d_model"])                                       # pre-norm
-    return c["n_layers"] * layer + c["vocab"] * c["d_model"] + c["d_model"]
-
-
-def _whisper_count(c):
-    """Whisper (openai/whisper-tiny): biased q, v, out; LayerNorms with
-    biases; a two-matrix MLP with biases; head tied to the embedding."""
-    m, ff = c["d_model"], c["d_ff"]
-    attn = 4 * m * m + 3 * m
-    mlp = 2 * m * ff + ff + m
-    ln = 2 * m
-    conv = (c["num_mel_bins"] * m * c["conv_width"] + m
-            + m * m * c["conv_width"] + m)
-    enc = conv + c["source_len"] * m + c["encoder_layers"] * (attn + mlp + 2 * ln) + ln
-    dec = (c["vocab"] * m + c["decoder_len"] * m
-           + c["n_layers"] * (2 * attn + mlp + 3 * ln) + ln)
-    return enc + dec
-
-
 @pytest.mark.parametrize("conf", SPEC["configs"], ids=lambda c: c["name"])
 def test_config_d_is_the_published_parameter_count(conf):
+    """d is the count that ``bench/configs/<name>.py`` works out from the
+    file's own numbers."""
     data = core.load_json(ROOT / conf["file"])
-    count = {"mamba2-130m": _mamba2_count, "whisper-tiny": _whisper_count}[data["name"]]
-    assert count(data) == data["d"]
+    path = core.BENCH_DIR / "configs" / f"{data['name']}.py"
+    assert path.is_file(), (f"configuration {data['name']!r} has no count "
+                            f"module: {path} is missing")
+    assert core.load_module("configs", data["name"]).count(data) == data["d"]
 
 
 def _run(cwd, env_extra):
@@ -132,3 +108,52 @@ def test_seed_key_keeps_every_bit():
     keys = {tuple(jax.random.key_data(core.seed_key(s)).tolist())
             for s in (0, 1, 2 ** 31 + 5, 2 ** 32 + 5, 5, 2 ** 40)}
     assert len(keys) == 6
+
+
+ROOM_CONFIG = "room-probe"
+ROOM_COUNT = '''
+def count(c):
+    inner = c["ssm_expand"] * c["d_model"]
+    conv_dim = inner + 2 * c["ssm_groups"] * c["ssm_state"]
+    layer = (c["d_model"] * (inner + conv_dim + c["ssm_heads"])
+             + conv_dim * (c["conv_width"] + 1) + 3 * c["ssm_heads"] + inner
+             + inner * c["d_model"] + c["d_model"])
+    return c["n_layers"] * layer + c["vocab"] * c["d_model"] + c["d_model"]
+'''
+
+
+def test_a_configuration_and_a_mix_join_by_files_alone(tmp_path):
+    """In a copy of the benchmark, a new configuration (its file and its
+    count module), a traffic mix of an existing path and a cell of both
+    pass the harness's own checks, with no file of the copy edited."""
+    shutil.copytree(core.BENCH_DIR, tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = {p: p.read_bytes() for p in (tmp_path / "bench").rglob("*") if p.is_file()}
+    conf = dict(core.load_json(core.BENCH_DIR / "configs" / "mamba2-130m.json"),
+                name=ROOM_CONFIG)
+    (tmp_path / "bench/configs" / f"{ROOM_CONFIG}.json").write_text(json.dumps(conf))
+    (tmp_path / "bench/configs" / f"{ROOM_CONFIG}.py").write_text(ROOM_COUNT)
+    mix = dict(core.load_json(core.BENCH_DIR / "workloads" / "n8.threshold.json"),
+               clients=4, a=1)
+    (tmp_path / "bench/workloads/n4.room.json").write_text(json.dumps(mix))
+    spec = json.loads(json.dumps(SPEC))
+    spec["configs"].append({"name": ROOM_CONFIG, "source": "https://example.org/room",
+                            "file": f"bench/configs/{ROOM_CONFIG}.json",
+                            "reduced": [], "why": "a throwaway configuration"})
+    spec["workloads"].append({"name": f"round.{ROOM_CONFIG}.n4", "config": ROOM_CONFIG,
+                              "traffic": "n4.room", "chips": 1, "why": "a throwaway cell"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(ROOT / "src"))
+    p = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-rA", "-p", "no:cacheprovider",
+         "-p", "no:randomly", "bench/tests/test_bench_harness.py",
+         "bench/tests/test_bench_work.py", "-k",
+         "cell_names or traffic_file or parameter_count or below_what"],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stdout[-4000:] + p.stderr[-2000:]
+    passed = [line for line in p.stdout.splitlines() if line.startswith("PASSED")]
+    for case in (f"round.{ROOM_CONFIG}.n4", "traffic_file_names_a_path[n4.room]",
+                 f"registry_models_parameter_count[{ROOM_CONFIG}]",
+                 f"published_parameter_count[{ROOM_CONFIG}]", "writes[n4.room]"):
+        assert any(case in line for line in passed), (case, passed)
+    assert all(p.read_bytes() == b for p, b in before.items())
